@@ -1,50 +1,28 @@
-"""Version-compat shims for the installed JAX.
+"""Shared spellings of the installed JAX's APIs (jax 0.9).
 
-The repo targets the current ``jax.shard_map`` API; older JAX (≤0.4.x,
-as shipped in this container) exposes shard_map under
-``jax.experimental.shard_map`` and names the replication-check kwarg
-``check_rep`` instead of ``check_vma``. Route every shard_map call
-through here so call sites stay on the modern spelling.
+Every shard_map call, mesh-axis lookup and compiled-cost read goes
+through here, so a later JAX that renames one of them changes one file.
+The ``compat-shim`` lint rule keeps version probes out of every other
+module.
 """
 from __future__ import annotations
 
 import jax
 
-if hasattr(jax, "shard_map"):
 
-    def shard_map(f, *, mesh, in_specs, out_specs, check_vma: bool = True):
-        return jax.shard_map(
-            f, mesh=mesh, in_specs=in_specs, out_specs=out_specs, check_vma=check_vma
-        )
-
-else:
-    from jax.experimental.shard_map import shard_map as _shard_map
-
-    def shard_map(f, *, mesh, in_specs, out_specs, check_vma: bool = True):
-        return _shard_map(
-            f, mesh=mesh, in_specs=in_specs, out_specs=out_specs, check_rep=check_vma
-        )
+def shard_map(f, *, mesh, in_specs, out_specs, check_vma: bool = True):
+    return jax.shard_map(
+        f, mesh=mesh, in_specs=in_specs, out_specs=out_specs, check_vma=check_vma
+    )
 
 
 def mesh_axis_size(mesh, axis: str, default: int = 1) -> int:
-    """Size of a named mesh axis, ``default`` if absent (or ``mesh`` is None).
-
-    Current JAX exposes ``Mesh.shape`` as a Mapping (``.get`` works); older
-    versions return a plain tuple-like, where sizes must be rebuilt from
-    ``axis_names``/``devices.shape``. All call sites go through here instead
-    of probing ``mesh.shape`` inline."""
+    """Size of a named mesh axis, ``default`` if absent (or ``mesh`` is None)."""
     if mesh is None:
         return default
-    shape = mesh.shape
-    if hasattr(shape, "get"):
-        return int(shape.get(axis, default))
-    return int(dict(zip(mesh.axis_names, mesh.devices.shape)).get(axis, default))
+    return int(mesh.shape.get(axis, default))
 
 
 def cost_analysis(compiled) -> dict:
-    """Normalized ``Compiled.cost_analysis()``: old JAX returns a one-element
-    list of dicts (one per program), current JAX returns the dict itself."""
-    ca = compiled.cost_analysis() or {}
-    if isinstance(ca, (list, tuple)):
-        ca = ca[0] if ca else {}
-    return ca
+    """``Compiled.cost_analysis()`` as a dict (empty when XLA gives none)."""
+    return compiled.cost_analysis() or {}

@@ -4,8 +4,8 @@ The paper profiles per-layer runtimes once per model (different batch
 sizes) and uses them for (a) ramp utility scoring and (b) translating exit
 locations into latency savings. On this CPU-only container we derive the
 profile analytically from the architecture's per-layer FLOPs / bytes and
-the TPU v5e roofline constants — the same model used in EXPERIMENTS.md
-§Roofline — so measured profiles can drop in unchanged on real hardware.
+a TPU v5e's published peaks (``PEAKS``, keyed by device kind), so
+measured profiles can drop in unchanged on real hardware.
 """
 from __future__ import annotations
 
@@ -14,10 +14,34 @@ from typing import Optional, Sequence, Tuple
 
 import numpy as np
 
-# TPU v5e (per chip)
-PEAK_FLOPS = 197e12  # bf16
-HBM_BW = 819e9  # B/s
-ICI_BW = 50e9  # B/s per link
+# Published per-chip peaks, keyed by ``jax.Device.device_kind``. Source:
+# Google Cloud documentation, "TPU v5e" (197 TFLOP/s bf16, 16 GB HBM at
+# 819 GB/s, 1,600 Gbit/s inter-chip interconnect = 4 links of 50 GB/s). A kind missing here is an error, never a default.
+PEAKS = {
+    "TPU v5 lite": {
+        "flops_bf16": 197e12,
+        "hbm_bytes": 16e9,
+        "hbm_bw": 819e9,  # B/s
+        "ici_bw_per_link": 50e9,  # B/s
+    },
+}
+
+# Planning on a host without the chip (the CPU tests, the simulated
+# serving clock) models a v5e explicitly.
+PLANNING_KIND = "TPU v5 lite"
+
+
+def peaks(device_kind: str) -> dict:
+    """Peaks of the chip ``jax.devices()[0].device_kind`` names."""
+    if device_kind not in PEAKS:
+        raise KeyError(f"no published peaks for device kind {device_kind!r}; "
+                       f"known: {sorted(PEAKS)}")
+    return PEAKS[device_kind]
+
+
+PEAK_FLOPS = PEAKS[PLANNING_KIND]["flops_bf16"]
+HBM_BW = PEAKS[PLANNING_KIND]["hbm_bw"]
+ICI_BW = PEAKS[PLANNING_KIND]["ici_bw_per_link"]
 
 
 def _layer_flops_bytes(

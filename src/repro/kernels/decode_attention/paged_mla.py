@@ -9,12 +9,13 @@ latent space (``q_lat = q_nope @ w_uk``), so the score is
     s[b, h, t] = (q_lat[b, h] . c[b, t] + q_pe[b, h] . k_pe[b, t]) * scale
 
 and the context is the probability-weighted latent ``sum_t p_t c[b, t]``
-— MQA-like: all H heads walk the same latent blocks, no GQA grouping.
+— MQA-like: all H heads walk the same latent blocks, no GQA grouping, so
+one program holds all H heads of a row and reads each latent block once.
 
-The block walk mirrors ``paged.py``: the per-row block table and
-positions ride in as scalar-prefetch operands so the latent BlockSpec
-index maps resolve ``table[b, j]`` before the tile DMA issues; the
-online-softmax running max / sum live in per-row output refs and the
+The block walk mirrors ``paged.py`` (grid ``(B, nb)``): the per-row block
+table and positions ride in as scalar-prefetch operands so the latent
+BlockSpec index maps resolve ``table[b, j]`` before the tile DMA issues;
+the online-softmax running max / sum live in VMEM scratch and the
 division happens on the last block. ``kpos <= pos`` masks both the
 partial last block and whole unallocated blocks (trash-block table
 entries), and ``c`` is zeroed under the mask so stale pool lanes cannot
@@ -35,44 +36,42 @@ from jax.experimental.pallas import tpu as pltpu
 NEG_INF = -1e30
 
 
-def _kernel(tab_ref, pos_ref, ql_ref, qp_ref, c_ref, kp_ref,
-            o_ref, m_ref, l_ref, *, bs, scale, nb, H):
+def _kernel(tab_ref, pos_ref, ql_ref, qp_ref, c_ref, kp_ref, o_ref, m_sc, l_sc,
+            *, bs, scale, nb):
     js = pl.program_id(1)
-    ql = ql_ref[0].astype(jnp.float32)  # (1, r)
-    qp = qp_ref[0].astype(jnp.float32)  # (1, dr)
+    pos = pos_ref[pl.program_id(0)]
+    ql = ql_ref[0].astype(jnp.float32)  # (H, r)
+    qp = qp_ref[0].astype(jnp.float32)  # (H, dr)
     c = c_ref[0].astype(jnp.float32)  # (bs, r)
     kp = kp_ref[0].astype(jnp.float32)  # (bs, dr)
-    pos = pos_ref[pl.program_id(0) // H]
+    nt = (((1,), (1,)), ((), ()))  # contract the feature dims: q @ k.T
     s = (
-        jnp.dot(ql, c.T, preferred_element_type=jnp.float32)
-        + jnp.dot(qp, kp.T, preferred_element_type=jnp.float32)
-    ) * scale  # (1, bs)
-    kpos = js * bs + jax.lax.broadcasted_iota(jnp.int32, (1, bs), 1)
+        jax.lax.dot_general(ql, c, nt, preferred_element_type=jnp.float32)
+        + jax.lax.dot_general(qp, kp, nt, preferred_element_type=jnp.float32)
+    ) * scale  # (H, bs)
+    kpos = js * bs + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
     mask = kpos <= pos
     s = jnp.where(mask, s, NEG_INF)
-    cv = jnp.where(mask[0][:, None], c, 0.0)  # value stream IS the latent
-    tile_m = jnp.max(s, axis=-1)
+    cpos = js * bs + jax.lax.broadcasted_iota(jnp.int32, c.shape, 0)
+    cv = jnp.where(cpos <= pos, c, 0.0)  # value stream IS the latent
 
     @pl.when(js == 0)
     def _init():
-        m_ref[0] = tile_m
-        p = jnp.where(mask, jnp.exp(s - tile_m[:, None]), 0.0)
-        l_ref[0] = jnp.sum(p, -1)
-        o_ref[0] = jnp.dot(p, cv, preferred_element_type=jnp.float32)
+        m_sc[...] = jnp.full(m_sc.shape, NEG_INF, jnp.float32)
+        l_sc[...] = jnp.zeros(l_sc.shape, jnp.float32)
+        o_ref[...] = jnp.zeros(o_ref.shape, jnp.float32)
 
-    @pl.when(js > 0)
-    def _step():
-        m_old = m_ref[0]
-        m_new = jnp.maximum(m_old, tile_m)
-        alpha = jnp.exp(m_old - m_new)
-        p = jnp.where(mask, jnp.exp(s - m_new[:, None]), 0.0)
-        l_ref[0] = l_ref[0] * alpha + jnp.sum(p, -1)
-        o_ref[0] = o_ref[0] * alpha[:, None] + jnp.dot(p, cv, preferred_element_type=jnp.float32)
-        m_ref[0] = m_new
+    m_old = m_sc[...]  # (H, 1)
+    m_new = jnp.maximum(m_old, jnp.max(s, axis=-1, keepdims=True))
+    alpha = jnp.exp(m_old - m_new)
+    p = jnp.where(mask, jnp.exp(s - m_new), 0.0)
+    l_sc[...] = l_sc[...] * alpha + jnp.sum(p, axis=-1, keepdims=True)
+    o_ref[0] = o_ref[0] * alpha + jnp.dot(p, cv, preferred_element_type=jnp.float32)
+    m_sc[...] = m_new
 
     @pl.when(js == nb - 1)
     def _final():
-        o_ref[0] = o_ref[0] / jnp.maximum(l_ref[0], 1e-30)[:, None]
+        o_ref[0] = o_ref[0] / jnp.maximum(l_sc[...], 1e-30)
 
 
 def paged_mla_decode_attention(
@@ -90,38 +89,35 @@ def paged_mla_decode_attention(
     dr = q_pe.shape[-1]
     P, bs, _ = c_pool.shape
     nb = block_table.shape[1]
-    qlf = q_lat.reshape(B * H, 1, r)
-    qpf = q_pe.reshape(B * H, 1, dr)
     table = jnp.asarray(block_table, jnp.int32)
     pos_arr = jnp.broadcast_to(jnp.asarray(pos, jnp.int32).reshape(-1), (B,))
 
-    def kv_map(bh, js, tab_ref, pos_ref):
-        return (tab_ref[bh // H, js], 0, 0)
+    def q_map(b, js, tab_ref, pos_ref):
+        return (b, 0, 0)
 
-    kernel = functools.partial(_kernel, bs=bs, scale=scale, nb=nb, H=H)
+    def kv_map(b, js, tab_ref, pos_ref):
+        return (tab_ref[b, js], 0, 0)
+
+    kernel = functools.partial(_kernel, bs=bs, scale=scale, nb=nb)
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,  # block table + per-row positions
-        grid=(B * H, nb),
+        grid=(B, nb),
         in_specs=[
-            pl.BlockSpec((1, 1, r), lambda bh, js, tab_ref, pos_ref: (bh, 0, 0)),
-            pl.BlockSpec((1, 1, dr), lambda bh, js, tab_ref, pos_ref: (bh, 0, 0)),
+            pl.BlockSpec((1, H, r), q_map),
+            pl.BlockSpec((1, H, dr), q_map),
             pl.BlockSpec((1, bs, r), kv_map),
             pl.BlockSpec((1, bs, dr), kv_map),
         ],
-        out_specs=[
-            pl.BlockSpec((1, 1, r), lambda bh, js, tab_ref, pos_ref: (bh, 0, 0)),
-            pl.BlockSpec((1, 1), lambda bh, js, tab_ref, pos_ref: (bh, 0)),
-            pl.BlockSpec((1, 1), lambda bh, js, tab_ref, pos_ref: (bh, 0)),
-        ],
+        out_specs=pl.BlockSpec((1, H, r), q_map),
+        scratch_shapes=[pltpu.VMEM((H, 1), jnp.float32),
+                        pltpu.VMEM((H, 1), jnp.float32)],
     )
-    o, m, l = pl.pallas_call(
+    o = pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
-        out_shape=[
-            jax.ShapeDtypeStruct((B * H, 1, r), jnp.float32),
-            jax.ShapeDtypeStruct((B * H, 1), jnp.float32),
-            jax.ShapeDtypeStruct((B * H, 1), jnp.float32),
-        ],
+        out_shape=jax.ShapeDtypeStruct((B, H, r), jnp.float32),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
-    )(table, pos_arr, qlf, qpf, c_pool, kpe_pool)
-    return o.reshape(B, H, r).astype(q_lat.dtype)
+    )(table, pos_arr, q_lat, q_pe, c_pool, kpe_pool)
+    return o.astype(q_lat.dtype)

@@ -9,7 +9,9 @@ O(1) memory.
 
 Grid: (B/bb, V/bv) with the vocab dimension innermost (sequential
 accumulation); batch tiles are parallel. All accumulators live in VMEM
-output blocks whose index map ignores the vocab index.
+output blocks whose index map ignores the vocab index. The vocab tile
+shrinks for wide heads so the double-buffered ``(d, bv)`` weight tile
+stays inside the TPU's scoped VMEM (``_vocab_tile``).
 """
 from __future__ import annotations
 
@@ -20,12 +22,36 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
 
+# VMEM the weight tile may take: two pipeline buffers plus, where h is
+# wider than w, the tile's in-kernel upcast (v5e scoped VMEM is 16 MiB)
+_W_TILE_VMEM = 12 * 2**20
+
+
+def _vocab_tile(d: int, V: int, h_dtype, w_dtype, block_v: int) -> int:
+    """Vocab tile: ``min(block_v, V)``, or, where its weight tile would
+    overflow ``_W_TILE_VMEM``, the largest multiple of 128 dividing V that
+    fits."""
+    cd = jnp.promote_types(h_dtype, w_dtype)
+    per_col = d * (2 * jnp.dtype(w_dtype).itemsize
+                   + (cd.itemsize if cd != jnp.dtype(w_dtype) else 0))
+    bv = min(block_v, V)
+    if bv * per_col <= _W_TILE_VMEM:
+        return bv
+    fit = [c for c in range(128, bv, 128) if V % c == 0 and c * per_col <= _W_TILE_VMEM]
+    if not fit:
+        raise ValueError(f"no vocab tile of V={V} fits VMEM at d={d}")
+    return fit[-1]
+
+
 def _kernel(h_ref, w_ref, m_ref, s_ref, t_ref, idx_ref, *, bv: int, v_limit: int):
     j = pl.program_id(1)
     h = h_ref[...]
     w = w_ref[...]
+    # operands meet in their common dtype: bf16 x bf16 products are exact
+    # in the f32 accumulator, so a bf16 weight tile is never upcast
+    cd = jnp.promote_types(h.dtype, w.dtype)
     logits = jnp.dot(
-        h.astype(jnp.float32), w.astype(jnp.float32), preferred_element_type=jnp.float32
+        h.astype(cd), w.astype(cd), preferred_element_type=jnp.float32
     )  # (bb, bv)
     bb = logits.shape[0]
     col = jax.lax.broadcasted_iota(jnp.int32, logits.shape, 1)
@@ -91,7 +117,7 @@ def ramp_head_stats(
     B, d = h.shape
     V = w.shape[1]
     bb = min(block_b, B)
-    bv = min(block_v, V)
+    bv = _vocab_tile(d, V, h.dtype, w.dtype, block_v)
     assert B % bb == 0 and V % bv == 0, (B, V, bb, bv)
     grid = (B // bb, V // bv)
     kernel = functools.partial(_kernel, bv=bv, v_limit=v_limit if v_limit is not None else V)
@@ -136,7 +162,7 @@ def ramp_head_exit(
     B, d = h.shape
     V = w.shape[1]
     bb = min(block_b, B)
-    bv = min(block_v, V)
+    bv = _vocab_tile(d, V, h.dtype, w.dtype, block_v)
     assert B % bb == 0 and V % bv == 0, (B, V, bb, bv)
     grid = (B // bb, V // bv)
     kernel = functools.partial(

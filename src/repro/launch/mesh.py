@@ -14,14 +14,11 @@ from repro.models.layers import MeshAxes
 
 
 def make_mesh(shape, axes):
-    """`jax.make_mesh` with explicit Auto axis types where the installed
-    JAX supports them (jax.sharding.AxisType landed after 0.4.x; older
-    versions already default every axis to Auto)."""
-    if hasattr(jax.sharding, "AxisType"):
-        return jax.make_mesh(
-            shape, axes, axis_types=(jax.sharding.AxisType.Auto,) * len(axes)
-        )
-    return jax.make_mesh(shape, axes)
+    """`jax.make_mesh` with every axis Auto (sharding propagated by XLA);
+    JAX now defaults mesh axes to Explicit."""
+    return jax.make_mesh(
+        shape, axes, axis_types=(jax.sharding.AxisType.Auto,) * len(axes)
+    )
 
 
 def make_production_mesh(*, multi_pod: bool = False):

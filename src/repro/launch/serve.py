@@ -20,6 +20,7 @@ import numpy as np
 from repro.configs import get_bench, get_config, get_tiny
 from repro.core import ApparateController, ControllerConfig, build_profile
 from repro.data import make_decode_stream, make_image_stream, make_token_stream
+from repro.launch.compile_cache import use_compile_cache
 from repro.launch.tuning import PRESETS, apply_preset
 from repro.models import build_model
 from repro.serving import (
@@ -129,6 +130,30 @@ def serve(domain: str, n: int, *, policy="tfserve", budget=0.02, acc=0.99,
     return out
 
 
+def build_generative_engine(model, params, prompts, profile, gcfg, ccfg, *,
+                            max_new_tokens, kv_block_size=0, kv_blocks=None,
+                            prefix_cache=False, mesh=None, admission=None):
+    """The served generative path: a ``DecodeRunner`` over ``model`` and
+    ``params`` (a ``ShardedDecodeRunner`` when ``mesh`` is given), an
+    ``ApparateController`` over the model's ramp sites, and the
+    ``GenerativeEngine`` that drives both with ``gcfg.max_batch_size``
+    decode slots and ``ccfg.max_slots`` ramp gather slots.
+    ``kv_block_size > 0`` pages the KV cache (the model config must then
+    pick a ``decode_attn='paged*'`` path). The runner and controller are
+    ``engine.runner`` and ``engine.controller``."""
+    rkw = dict(max_new_tokens=max_new_tokens, max_slots=ccfg.max_slots,
+               n_slots=gcfg.max_batch_size)
+    if kv_block_size:
+        rkw.update(kv_block_size=kv_block_size, kv_blocks=kv_blocks,
+                   prefix_cache=prefix_cache)
+    if mesh is not None:
+        runner = ShardedDecodeRunner(model, params, prompts, mesh=mesh, **rkw)
+    else:
+        runner = DecodeRunner(model, params, prompts, **rkw)
+    ctl = ApparateController(len(model.sites), profile, ccfg)
+    return GenerativeEngine(profile, gcfg, runner, ctl, admission=admission)
+
+
 def serve_generative(n=48, *, decode_tokens=16, budget=0.02, acc=0.99, load=0.5,
                      seed=2, slots=4, layers=6, kv_block_size=0, kv_blocks=None,
                      prefill_chunk=0, admission=False, admission_slack=1.0,
@@ -218,24 +243,17 @@ def serve_generative(n=48, *, decode_tokens=16, budget=0.02, acc=0.99, load=0.5,
 
     base_eng = GenerativeEngine(prof, gcfg, admission=adm())
     mb = summarize_generative(base_eng.run(reqs), horizon_ms=base_eng.makespan_ms)
-    ctl = ApparateController(ns, prof, ControllerConfig(
-        max_slots=slots, ramp_budget_frac=budget, acc_constraint=acc))
-    rkw = {}
-    if kv_block_size:
-        rkw = dict(kv_block_size=kv_block_size, kv_blocks=kv_blocks,
-                   prefix_cache=prefix_cache)
+    mesh = None
     if tp > 1 or dp > 1:
         from repro.launch.mesh import make_serving_mesh
-        runner = ShardedDecodeRunner(
-            model, state["params"], stream.data[:, :seq_len],
-            mesh=make_serving_mesh(tp=tp, dp=dp),
-            max_new_tokens=decode_tokens + 2, max_slots=slots,
-            n_slots=mbs, **rkw)
-    else:
-        runner = DecodeRunner(model, state["params"], stream.data[:, :seq_len],
-                              max_new_tokens=decode_tokens + 2, max_slots=slots,
-                              n_slots=mbs, **rkw)
-    eng = GenerativeEngine(prof, gcfg, runner, ctl, admission=adm())
+        mesh = make_serving_mesh(tp=tp, dp=dp)
+    eng = build_generative_engine(
+        model, state["params"], stream.data[:, :seq_len], prof, gcfg,
+        ControllerConfig(max_slots=slots, ramp_budget_frac=budget, acc_constraint=acc),
+        max_new_tokens=decode_tokens + 2, kv_block_size=kv_block_size,
+        kv_blocks=kv_blocks, prefix_cache=prefix_cache, mesh=mesh,
+        admission=adm())
+    runner, ctl = eng.runner, eng.controller
     mo = summarize_generative(eng.run(reqs), horizon_ms=eng.makespan_ms)
     out = {
         "mode": "generative", "n": n, "decode_tokens": decode_tokens,
@@ -384,6 +402,7 @@ def main(argv=None):
     args = ap.parse_args(argv)
     # env presets must land before any jax backend work in the run
     apply_preset(args.runtime_preset)
+    use_compile_cache()
     if args.mesh_shape:
         try:
             args.dp, args.tp = (int(x) for x in args.mesh_shape.lower().split("x"))

@@ -8,6 +8,7 @@ dry-run lowering. This guarantees params and shardings never drift.
 from __future__ import annotations
 
 import dataclasses
+import functools
 import math
 from typing import Any, Callable, Optional, Sequence
 
@@ -28,25 +29,39 @@ class ParamInfo:
     init: str = "normal:0.02"
 
     def initialize(self, key: jax.Array) -> jax.Array:
-        kind, _, arg = self.init.partition(":")
-        if kind == "zeros":
-            return jnp.zeros(self.shape, self.dtype)
-        if kind == "ones":
-            return jnp.ones(self.shape, self.dtype)
-        if kind in ("normal", "embed"):
-            scale = float(arg) if arg else 0.02
-            # fan-in scaled init for 2D+ weights
-            x = jax.random.normal(key, self.shape, jnp.float32) * scale
-            return x.astype(self.dtype)
-        if kind == "ssm_a":  # A_log init in [log(1), log(16)) per Mamba2
-            lo, hi = 1.0, 16.0
-            u = jax.random.uniform(key, self.shape, jnp.float32)
-            return jnp.log(lo + u * (hi - lo)).astype(self.dtype)
-        if kind == "dt_bias":  # softplus^-1 of dt in [1e-3, 1e-1]
-            u = jax.random.uniform(key, self.shape, jnp.float32)
-            dt = jnp.exp(u * (math.log(1e-1) - math.log(1e-3)) + math.log(1e-3))
-            return (dt + jnp.log(-jnp.expm1(-dt))).astype(self.dtype)
-        raise ValueError(f"unknown init {self.init!r}")
+        dtype = jnp.dtype(self.dtype)
+        # an f32 leaf is its own draw; a narrower one is cast inside one
+        # jitted program, so the f32 draw fuses into the cast and never
+        # exists as a whole-leaf array (a full-vocabulary ramp-head stack
+        # is larger in f32 than a 16 GB chip)
+        draw = _draw if dtype == jnp.float32 else _draw_fused
+        return draw(key, tuple(self.shape), dtype, self.init)
+
+
+def _draw(key, shape, dtype, init):
+    """One leaf's initial value, drawn in f32 and cast to ``dtype``."""
+    kind, _, arg = init.partition(":")
+    if kind == "zeros":
+        return jnp.zeros(shape, dtype)
+    if kind == "ones":
+        return jnp.ones(shape, dtype)
+    if kind in ("normal", "embed"):
+        scale = float(arg) if arg else 0.02
+        # fan-in scaled init for 2D+ weights
+        x = jax.random.normal(key, shape, jnp.float32) * scale
+        return x.astype(dtype)
+    if kind == "ssm_a":  # A_log init in [log(1), log(16)) per Mamba2
+        lo, hi = 1.0, 16.0
+        u = jax.random.uniform(key, shape, jnp.float32)
+        return jnp.log(lo + u * (hi - lo)).astype(dtype)
+    if kind == "dt_bias":  # softplus^-1 of dt in [1e-3, 1e-1]
+        u = jax.random.uniform(key, shape, jnp.float32)
+        dt = jnp.exp(u * (math.log(1e-1) - math.log(1e-3)) + math.log(1e-3))
+        return (dt + jnp.log(-jnp.expm1(-dt))).astype(dtype)
+    raise ValueError(f"unknown init {init!r}")
+
+
+_draw_fused = jax.jit(_draw, static_argnums=(1, 2, 3))
 
 
 def is_info(x) -> bool:

@@ -740,9 +740,13 @@ class LM(MultiStepDecodeMixin):
         block_tables=None,
         remat=False,
         tp: Optional[TpCtx] = None,
+        layer_params=None,
     ):
         """Run prefix + scanned periods + suffix. Returns
-        (h, pooled (L,B,npos,d), new_caches, aux)."""
+        (h, pooled (L,B,npos,d), new_caches, aux). ``layer_params(kind, i,
+        p)`` maps the params ``p`` of layer slot ``i`` of ``kind``
+        ('prefix' | 'blocks' | 'suffix'; one period's slice for 'blocks')
+        to the params the layer runs with."""
         cfg, plan = self.cfg, self.plan
         pooled_all: List = []
         aux_total = jnp.zeros((), jnp.float32)
@@ -755,12 +759,14 @@ class LM(MultiStepDecodeMixin):
             axes=axes, mesh=mesh, cache_index=cache_index, memory=memory,
             moe_impl=moe_impl, block_tables=block_tables, tp=tp,
         )
+        lp = layer_params or (lambda kind, i, p: p)
         new_caches: Dict[str, Any] = {}
         if plan.prefix:
             new_caches["prefix"] = []
             for i, slot in enumerate(plan.prefix):
                 c = caches["prefix"][i] if caches else None
-                h, nc, a = self._block(slot, params["prefix"][i], h, cache=c, **kw)
+                h, nc, a = self._block(slot, lp("prefix", i, params["prefix"][i]),
+                                       h, cache=c, **kw)
                 new_caches["prefix"].append(nc)
                 aux_total = aux_total + a
                 pooled_all.append(pool(h))
@@ -771,7 +777,8 @@ class LM(MultiStepDecodeMixin):
             pooled_s, cout = [], []
             for s, slot in enumerate(plan.period):
                 c = cblocks[s] if cblocks is not None else None
-                hh, nc, a = self._block(slot, pblocks[s], hh, cache=c, **kw)
+                hh, nc, a = self._block(slot, lp("blocks", s, pblocks[s]), hh,
+                                        cache=c, **kw)
                 auxc = auxc + a
                 pooled_s.append(pool(hh))
                 cout.append(nc if nc is not None else 0)
@@ -797,7 +804,8 @@ class LM(MultiStepDecodeMixin):
             new_caches["suffix"] = []
             for i, slot in enumerate(plan.suffix):
                 c = caches["suffix"][i] if caches else None
-                h, nc, a = self._block(slot, params["suffix"][i], h, cache=c, **kw)
+                h, nc, a = self._block(slot, lp("suffix", i, params["suffix"][i]),
+                                       h, cache=c, **kw)
                 new_caches["suffix"].append(nc)
                 aux_total = aux_total + a
                 pooled_all.append(pool(h))
@@ -896,9 +904,12 @@ class LM(MultiStepDecodeMixin):
 
     def prefill(self, params, tokens, *, cache_len=None, active_sites=None,
                 axes=LY.TEST_AXES, mesh=None, moe_impl="ep", image_embeds=None,
-                shard_batch=True, with_cache=True):
+                shard_batch=True, with_cache=True, layer_params=None):
         """tokens: (B,S). Returns (cache|None, outs) where outs carries final
-        + per-active-ramp stats for the LAST position (the generated token)."""
+        + per-active-ramp stats for the LAST position (the generated token).
+        ``layer_params`` (see ``_stack``) rebuilds each layer's weights
+        just before the layer runs — the tensor-parallel runner gathers a
+        layer's shards there, so no device holds the whole model at once."""
         cfg = self.cfg
         B, S = tokens.shape
         cache_len = cache_len or S
@@ -923,6 +934,7 @@ class LM(MultiStepDecodeMixin):
             params, h, positions=positions, mask_full=mask_full,
             mask_local=mask_local, axes=axes, mesh=mesh, caches=caches,
             cache_index=0, memory=memory, moe_impl=moe_impl, pool_idx=pool_idx,
+            layer_params=layer_params,
         )
         outs = self._head_stats(params, h[:, -1:], pooled, active_sites,
                                 axes=axes, mesh=mesh)
@@ -930,7 +942,8 @@ class LM(MultiStepDecodeMixin):
 
     def decode(self, params, cache, tokens, pos, *, active_sites=None,
                axes=LY.TEST_AXES, mesh=None, moe_impl="ep", block_tables=None,
-               exit_thresholds=None, tp: Optional[TpCtx] = None):
+               exit_thresholds=None, tp: Optional[TpCtx] = None,
+               with_logits=False):
         """One decode step. tokens: (B,1); pos: int32 scalar (shared write
         index) or int32[B] per-row write indices — batched slot caches where
         continuous batching leaves every row at its own position (each row
@@ -940,8 +953,9 @@ class LM(MultiStepDecodeMixin):
         block pool from ``init_paged_cache``: each row's token scatters to
         ``(block_tables[b, pos[b] // bs], pos[b] % bs)`` and attention walks
         the table (``cfg.decode_attn`` must be a 'paged*' variant); masks
-        are internal to the paged kernel, so none are built here. Returns
-        (new_cache, outs)."""
+        are internal to the paged kernel, so none are built here.
+        ``with_logits`` adds the final head's f32 logits (B, Vp) as
+        ``outs["final"]["logits"]`` (dense head). Returns (new_cache, outs)."""
         cfg = self.cfg
         B, S = tokens.shape
         assert S == 1
@@ -962,7 +976,8 @@ class LM(MultiStepDecodeMixin):
             )
             outs = self._head_stats(params, h, pooled, active_sites,
                                     axes=axes, mesh=mesh,
-                                    exit_thresholds=exit_thresholds)
+                                    exit_thresholds=exit_thresholds,
+                                    with_logits=with_logits)
             return new_cache, outs
         # cache length from any attn cache leaf (mamba-only models have none)
         try:
@@ -990,7 +1005,8 @@ class LM(MultiStepDecodeMixin):
         )
         outs = self._head_stats(params, h, pooled, active_sites,
                                 axes=axes, mesh=mesh,
-                                exit_thresholds=exit_thresholds)
+                                exit_thresholds=exit_thresholds,
+                                with_logits=with_logits)
         return new_cache, outs
 
     # -- sharded (tensor-parallel) decode ------------------------------------
@@ -1118,7 +1134,8 @@ class LM(MultiStepDecodeMixin):
 
     def decode_sharded(self, params, cache, tokens, pos, *, mesh,
                        axes=LY.TEST_AXES, active_sites=None, moe_impl="dense",
-                       block_tables=None, exit_thresholds=None):
+                       block_tables=None, exit_thresholds=None,
+                       with_logits=False):
         """One decode step through ``shard_map`` on a ``(data, model)``
         mesh: tensor-parallel attention/MLP with the KV cache (contiguous
         or paged pool) sharded by kv head, bit-identical to single-device
@@ -1157,7 +1174,7 @@ class LM(MultiStepDecodeMixin):
             return self.decode(
                 p, c, toks, po, active_sites=act, axes=axes, mesh=None,
                 moe_impl=moe_impl, block_tables=tb, exit_thresholds=thr,
-                tp=ctx,
+                tp=ctx, with_logits=with_logits,
             )
 
         return shard_map(body, mesh=mesh, in_specs=tuple(specs),
@@ -1217,7 +1234,8 @@ class LM(MultiStepDecodeMixin):
                          check_vma=False)(*args)
 
     def _head_stats(self, params, h_last, pooled, active_sites,
-                    axes=None, mesh=None, exit_thresholds=None):
+                    axes=None, mesh=None, exit_thresholds=None,
+                    with_logits=False):
         """Final + ramp confidence stats for serving. h_last: (B,1,d).
 
         With cfg.pallas_head != 'off', stats stream through the fused
@@ -1233,7 +1251,7 @@ class LM(MultiStepDecodeMixin):
         ``simulate_exits``."""
         cfg = self.cfg
         h = LY.apply_norm(cfg, params["final_norm"], h_last)
-        if cfg.pallas_head != "off":
+        if cfg.pallas_head != "off" and not with_logits:
             return self._head_stats_pallas(params, h, pooled, active_sites,
                                            exit_thresholds=exit_thresholds)
         logits = LY.unembed(cfg, params["tok"], h)[:, 0].astype(jnp.float32)
@@ -1241,6 +1259,8 @@ class LM(MultiStepDecodeMixin):
             logits = LY.constrain(logits, axes.aspec("data", "model"), mesh)
         logits = _mask_pad_vocab(cfg, logits)
         outs = {"final": _stats(logits)}
+        if with_logits:
+            outs["final"]["logits"] = logits
         if active_sites is not None:
             rl = self.ramp_outputs(params, pooled, site_idx=active_sites,
                                    axes=axes, mesh=mesh)

@@ -941,18 +941,11 @@ class DecodeRunner:
             self._dec0 = dec0
         return self._dec0
 
-    def _donate_cache(self):
-        """Donate the cache/pool operand to the multi-step program so the
-        while_loop reuses its buffers in place (the runner always rebinds
-        ``self._cache`` from the result). CPU XLA does not implement
-        donation and would warn per dispatch — skip it there."""
-        return (1,) if jax.default_backend() != "cpu" else ()
-
     def _decode_multi_fn(self, n_max: int):
         if n_max not in self._decm:
             m = self.model
 
-            @partial(jax.jit, donate_argnums=self._donate_cache())  # repro: allow[jit-cache-hygiene] — wrapper memoized by the enclosing runner
+            @partial(jax.jit, donate_argnums=1)  # repro: allow[jit-cache-hygiene] — wrapper memoized by the enclosing runner
             def decm(params, big, toks, pos, rows, active, thr, n, valid):
                 sub = self._tree_take(big, rows)
                 sub, outs = m.decode_multi(
@@ -970,7 +963,7 @@ class DecodeRunner:
         if n_max not in self._decm0:
             m = self.model
 
-            @partial(jax.jit, donate_argnums=self._donate_cache())  # repro: allow[jit-cache-hygiene] — wrapper memoized by the enclosing runner
+            @partial(jax.jit, donate_argnums=1)  # repro: allow[jit-cache-hygiene] — wrapper memoized by the enclosing runner
             def decm0(params, big, toks, pos, rows, n, valid):
                 sub = self._tree_take(big, rows)
                 sub, outs = m.decode_multi(
@@ -987,7 +980,7 @@ class DecodeRunner:
         if n_max not in self._decm:
             m = self.model
 
-            @partial(jax.jit, donate_argnums=self._donate_cache())  # repro: allow[jit-cache-hygiene] — wrapper memoized by the enclosing runner
+            @partial(jax.jit, donate_argnums=1)  # repro: allow[jit-cache-hygiene] — wrapper memoized by the enclosing runner
             def decm(params, pools, toks, pos, tables, active, thr, n, valid):
                 pools, outs = m.decode_multi(
                     params, pools, toks, pos, n, n_max=n_max,
@@ -1003,7 +996,7 @@ class DecodeRunner:
         if n_max not in self._decm0:
             m = self.model
 
-            @partial(jax.jit, donate_argnums=self._donate_cache())  # repro: allow[jit-cache-hygiene] — wrapper memoized by the enclosing runner
+            @partial(jax.jit, donate_argnums=1)  # repro: allow[jit-cache-hygiene] — wrapper memoized by the enclosing runner
             def decm0(params, pools, toks, pos, tables, n, valid):
                 pools, outs = m.decode_multi(
                     params, pools, toks, pos, n, n_max=n_max,
@@ -1648,10 +1641,12 @@ class ShardedDecodeRunner(DecodeRunner):
     ``DecodeRunner`` over any schedule — the property the fuzz harness
     pins at tp=2 and tp=4.
 
-    Prefill runs REPLICATED inside the same shard_map (params enter
-    under ``P()``), then each device slices its own kv-head block out of
-    the freshly computed cache before scattering into its local shard —
-    one dispatch per admit, no separate resharding step.
+    Prefill runs REPLICATED inside the same shard_map: params enter
+    sharded as decode holds them, each layer's shards are gathered just
+    before the layer runs (``_prefill_params``), and each device slices
+    its own kv-head block out of the freshly computed cache before
+    scattering into its local shard — one dispatch per admit, and no
+    device holds the whole model.
 
     ``dp > 1`` (contiguous caches only — a data-sharded paged pool would
     diverge the replicated pool copies) additionally shards decode rows
@@ -1693,12 +1688,6 @@ class ShardedDecodeRunner(DecodeRunner):
         # a data-sharded step needs >= dp rows to gather from
         super()._ensure_rows(max(n, self.dp))
 
-    @staticmethod
-    def _rep_specs(tree):
-        from jax.sharding import PartitionSpec as P
-
-        return jax.tree.map(lambda _: P(), tree)
-
     def kv_stats(self) -> dict:
         out = super().kv_stats()
         out["tp"] = self.tp
@@ -1719,6 +1708,43 @@ class ShardedDecodeRunner(DecodeRunner):
 
     # -- jitted programs (shard_map variants) --------------------------------
 
+    def _prefill_params(self):
+        """Param specs and per-layer gather for prefill. Params enter
+        sharded as decode holds them; each layer's shards are gathered
+        just before it runs, so every device computes the whole prompt
+        exactly as the single-device runner does (records stay
+        bit-identical) while holding one layer's full weights at a time,
+        never the whole model."""
+        from jax.sharding import PartitionSpec as P
+
+        pspecs = self.model.tp_param_specs(self._maxes)
+        ax = self._maxes.model
+
+        def gather_leaf(x, sp):
+            if ax in tuple(sp):
+                return jax.lax.all_gather(x, ax, axis=tuple(sp).index(ax), tiled=True)
+            return x
+
+        def gather(kind, i, p):
+            sp = pspecs[kind][i]
+            if kind == "blocks":  # one period's slice: drop the layer axis
+                sp = jax.tree.map(lambda s: P(*tuple(s)[1:]), sp,
+                                  is_leaf=lambda s: isinstance(s, P))
+            return jax.tree.map(gather_leaf, p, sp,
+                                is_leaf=lambda s: isinstance(s, P))
+
+        return pspecs, gather
+
+    def _local_heads(self, cache):
+        """This device's kv-head block of a full prefill cache (the kv-head
+        axis is ndim-2 of every leaf ``tp_check`` admits)."""
+        mi = jax.lax.axis_index(self._maxes.model)
+        return jax.tree.map(
+            lambda x: jax.lax.dynamic_slice_in_dim(
+                x, mi * (x.shape[x.ndim - 2] // self.tp),
+                x.shape[x.ndim - 2] // self.tp, axis=x.ndim - 2),
+            cache)
+
     def _prefill_fn(self):
         if self._pf is None:
             from jax.sharding import PartitionSpec as P
@@ -1726,23 +1752,16 @@ class ShardedDecodeRunner(DecodeRunner):
             from repro.compat import shard_map
 
             m, cache_len = self.model, self._cache_len
-            mesh, axes, tpn = self.mesh, self._maxes, self.tp
+            mesh, axes = self.mesh, self._maxes
+            pspecs, gather = self._prefill_params()
             runner = self
 
             def body(params, big, toks, slot):
                 cache, outs = m.prefill(
                     params, toks, cache_len=cache_len, active_sites=None,
-                    with_cache=True, moe_impl="dense",
+                    with_cache=True, moe_impl="dense", layer_params=gather,
                 )
-                mi = jax.lax.axis_index(axes.model)
-                cache = jax.tree.map(
-                    lambda x: jax.lax.dynamic_slice_in_dim(
-                        x, mi * (x.shape[x.ndim - 2] // tpn),
-                        x.shape[x.ndim - 2] // tpn, axis=x.ndim - 2,
-                    ),
-                    cache,
-                )
-                big = runner._tree_put(big, cache, slot[None])
+                big = runner._tree_put(big, runner._local_heads(cache), slot[None])
                 lab = outs["final"]["label"]
                 return big, (lab[:, 0] if lab.ndim == 2 else lab)
 
@@ -1751,7 +1770,7 @@ class ShardedDecodeRunner(DecodeRunner):
                 cspecs = m.tp_cache_specs(big, axes)
                 return shard_map(
                     body, mesh=mesh,
-                    in_specs=(self._rep_specs(params), cspecs, P(), P()),
+                    in_specs=(pspecs, cspecs, P(), P()),
                     out_specs=(cspecs, P()), check_vma=False,
                 )(params, big, toks, slot)
 
@@ -1766,7 +1785,8 @@ class ShardedDecodeRunner(DecodeRunner):
             from repro.compat import shard_map
 
             m, cache_len = self.model, self._cache_len
-            mesh, axes, tpn = self.mesh, self._maxes, self.tp
+            mesh, axes = self.mesh, self._maxes
+            pspecs, gather = self._prefill_params()
             bs = self._bs_blk
             nb_pf = -(-n_tokens // bs)
             paxes = self._pool_axes
@@ -1788,16 +1808,9 @@ class ShardedDecodeRunner(DecodeRunner):
             def body(params, pools, toks, blk_ids, xkv_ids):
                 cache, outs = m.prefill(
                     params, toks, cache_len=cache_len, active_sites=None,
-                    with_cache=True, moe_impl="dense",
+                    with_cache=True, moe_impl="dense", layer_params=gather,
                 )
-                mi = jax.lax.axis_index(axes.model)
-                cache = jax.tree.map(
-                    lambda x: jax.lax.dynamic_slice_in_dim(
-                        x, mi * (x.shape[x.ndim - 2] // tpn),
-                        x.shape[x.ndim - 2] // tpn, axis=x.ndim - 2,
-                    ),
-                    cache,
-                )
+                cache = self._local_heads(cache)
                 leaves, td = jax.tree.flatten(pools)
                 cl = jax.tree.leaves(cache)
                 out = [
@@ -1813,7 +1826,7 @@ class ShardedDecodeRunner(DecodeRunner):
                 cspecs = m.tp_cache_specs(pools, axes)
                 return shard_map(
                     body, mesh=mesh,
-                    in_specs=(self._rep_specs(params), cspecs, P(), P(), P()),
+                    in_specs=(pspecs, cspecs, P(), P(), P()),
                     out_specs=(cspecs, P()), check_vma=False,
                 )(params, pools, toks, blk_ids, xkv_ids)
 
@@ -1896,7 +1909,7 @@ class ShardedDecodeRunner(DecodeRunner):
         if n_max not in self._decm:
             m, mesh, axes = self.model, self.mesh, self._maxes
 
-            @partial(jax.jit, donate_argnums=self._donate_cache())  # repro: allow[jit-cache-hygiene] — wrapper memoized by the enclosing runner
+            @partial(jax.jit, donate_argnums=1)  # repro: allow[jit-cache-hygiene] — wrapper memoized by the enclosing runner
             def decm(params, big, toks, pos, rows, active, thr, n, valid):
                 sub = self._tree_take(big, rows)
                 sub, outs = m.decode_sharded_multi(
@@ -1914,7 +1927,7 @@ class ShardedDecodeRunner(DecodeRunner):
         if n_max not in self._decm0:
             m, mesh, axes = self.model, self.mesh, self._maxes
 
-            @partial(jax.jit, donate_argnums=self._donate_cache())  # repro: allow[jit-cache-hygiene] — wrapper memoized by the enclosing runner
+            @partial(jax.jit, donate_argnums=1)  # repro: allow[jit-cache-hygiene] — wrapper memoized by the enclosing runner
             def decm0(params, big, toks, pos, rows, n, valid):
                 sub = self._tree_take(big, rows)
                 sub, outs = m.decode_sharded_multi(
@@ -1932,7 +1945,7 @@ class ShardedDecodeRunner(DecodeRunner):
         if n_max not in self._decm:
             m, mesh, axes = self.model, self.mesh, self._maxes
 
-            @partial(jax.jit, donate_argnums=self._donate_cache())  # repro: allow[jit-cache-hygiene] — wrapper memoized by the enclosing runner
+            @partial(jax.jit, donate_argnums=1)  # repro: allow[jit-cache-hygiene] — wrapper memoized by the enclosing runner
             def decm(params, pools, toks, pos, tables, active, thr, n, valid):
                 pools, outs = m.decode_sharded_multi(
                     params, pools, toks, pos, n, mesh=mesh, n_max=n_max,
@@ -1948,7 +1961,7 @@ class ShardedDecodeRunner(DecodeRunner):
         if n_max not in self._decm0:
             m, mesh, axes = self.model, self.mesh, self._maxes
 
-            @partial(jax.jit, donate_argnums=self._donate_cache())  # repro: allow[jit-cache-hygiene] — wrapper memoized by the enclosing runner
+            @partial(jax.jit, donate_argnums=1)  # repro: allow[jit-cache-hygiene] — wrapper memoized by the enclosing runner
             def decm0(params, pools, toks, pos, tables, n, valid):
                 pools, outs = m.decode_sharded_multi(
                     params, pools, toks, pos, n, mesh=mesh, n_max=n_max,

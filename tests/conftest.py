@@ -16,9 +16,6 @@ def pytest_configure(config):
         "markers",
         "slow: long-running (interpret-mode Pallas sweeps); skipped unless -m mentions 'slow'",
     )
-    config.addinivalue_line(
-        "markers", "flaky: tolerated-rerun annotation (no-op without a rerun plugin)"
-    )
 
 
 def pytest_collection_modifyitems(config, items):

@@ -433,3 +433,34 @@ def test_summarize_generative_all_exited_at_site_zero():
     assert out["exit_rate"] == 1.0 and out["agreement"] == 1.0
     assert out["tpt_p50_ms"] == pytest.approx(1.0)
     assert out["tpt_slo_miss_rate"] == 0.0
+
+
+@pytest.mark.parametrize("layout", ["contiguous", "paged"])
+def test_sync_window_donates_cache(layout):
+    """A sync window donates the slot cache (contiguous rows or the paged
+    pool) to its program on every backend: the input buffers are deleted
+    after the dispatch, the runner rebinds the program's output, and the
+    next window runs on it."""
+    import jax
+
+    from repro.models import build_model
+    from repro.serving import DecodeRunner
+
+    paged = layout == "paged"
+    cfg = get_tiny("qwen2-1.5b").replace(
+        n_layers=2, vocab_size=64, decode_attn="paged" if paged else "ref")
+    model = build_model(cfg)
+    params = model.init(jax.random.PRNGKey(0))
+    prompts = np.random.default_rng(0).integers(0, 64, (2, 6)).astype(np.int32)
+    r = DecodeRunner(model, params, prompts, max_new_tokens=8, max_slots=1,
+                     kv_block_size=4)
+    r.start(0, 0)
+    r.start(1, 1)
+    thr = np.zeros(1, np.float32)
+    before = jax.tree.leaves(r._cache)
+    _, _, f1, _ = r.step_multi([0, 1], [0], 4, thr)
+    assert all(x.is_deleted() for x in before)
+    assert not any(x.is_deleted() for x in jax.tree.leaves(r._cache))
+    _, _, f2, _ = r.step_multi([0, 1], [0], 2, thr)
+    assert f1.shape == (4, 2) and f2.shape == (2, 2)
+    assert list(r._pos[:2]) == [12, 12]

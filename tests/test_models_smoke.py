@@ -160,3 +160,19 @@ def test_mla_absorbed_equivalence():
     assert (
         np.asarray(o_abs["final"]["label"]) == np.asarray(o_naive["final"]["label"])
     ).all()
+
+
+@pytest.mark.parametrize("init", ["normal:0.02", "ssm_a", "dt_bias", "ones"])
+@pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+def test_param_init_matches_eager_draw(init, dtype):
+    """A leaf drawn in one jitted program (narrow dtypes, so a whole-leaf
+    f32 draw never exists on the device) holds the values of the eager
+    f32 draw cast to its dtype."""
+    from repro.models.common import ParamInfo, _draw
+
+    info = ParamInfo(shape=(16, 24), dtype=dtype, init=init)
+    key = jax.random.PRNGKey(3)
+    got = info.initialize(key)
+    want = _draw(key, (16, 24), jnp.dtype(dtype), init)
+    assert got.dtype == jnp.dtype(dtype)
+    np.testing.assert_array_equal(np.asarray(got, np.float32), np.asarray(want, np.float32))
