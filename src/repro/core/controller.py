@@ -20,6 +20,7 @@ from typing import Dict, List, Optional, Sequence, Tuple
 
 import numpy as np
 
+from repro import tracing
 from repro.core.exits import RecordWindow, evaluate_config, simulate_exits
 from repro.core.ramp_adjust import adjust_ramps
 from repro.core.threshold_tuning import tune_thresholds
@@ -59,9 +60,7 @@ class ApparateController:
             "adjusts": 0,
             "ramp_changes": 0,
             "samples": 0,
-            "tune_wall_s": 0.0,
         }
-        self.history: List[dict] = []
 
     # -- initial placement (paper §3.1: evenly space max allowable ramps) ----
 
@@ -113,34 +112,36 @@ class ApparateController:
         records were GATHERED under: a mid-window ``_adjust`` can change
         ``self.active``, and later replayed steps of that window must
         still land their rows against the sites that produced them."""
-        act = list(self.active) if act is None else list(act)
-        B = final_labels.shape[0]
-        K = len(act)
-        correct = ramp_labels[:K] == final_labels[None, :]
-        self.window.append(act, ramp_unc[:K], correct)
-        self.stats["samples"] += B
-        self._since_adjust += B
+        with tracing.span("controller.decide"):
+            act = list(self.active) if act is None else list(act)
+            B = final_labels.shape[0]
+            K = len(act)
+            correct = ramp_labels[:K] == final_labels[None, :]
+            self.window.append(act, ramp_unc[:K], correct)
+            self.stats["samples"] += B
+            self._since_adjust += B
 
-        # decisions for THIS batch under current thresholds
-        unc_m = np.full((B, self.n_sites), np.nan, np.float32)
-        val_m = np.zeros((B, self.n_sites), bool)
-        cor_m = np.zeros((B, self.n_sites), bool)
-        for j, s in enumerate(act):
-            unc_m[:, s] = ramp_unc[j]
-            val_m[:, s] = True
-            cor_m[:, s] = correct[j]
-        if forced_exits is None:
-            ex = simulate_exits(unc_m, val_m, self.thresholds, act)
-        else:
-            ex = np.asarray(forced_exits, np.int64).copy()
-        released = np.asarray(final_labels).copy()
-        for j, s in enumerate(act):
-            m = ex == s
-            released[m] = ramp_labels[j][m]
+            # decisions for THIS batch under current thresholds
+            unc_m = np.full((B, self.n_sites), np.nan, np.float32)
+            val_m = np.zeros((B, self.n_sites), bool)
+            cor_m = np.zeros((B, self.n_sites), bool)
+            for j, s in enumerate(act):
+                unc_m[:, s] = ramp_unc[j]
+                val_m[:, s] = True
+                cor_m[:, s] = correct[j]
+            if forced_exits is None:
+                ex = simulate_exits(unc_m, val_m, self.thresholds, act)
+            else:
+                ex = np.asarray(forced_exits, np.int64).copy()
+            released = np.asarray(final_labels).copy()
+            for j, s in enumerate(act):
+                m = ex == s
+                released[m] = ramp_labels[j][m]
 
         # --- monitor: windowed accuracy triggers tuning (paper 16 samples)
-        wd = self.window.last(self.cfg.monitor_window)
-        mon = evaluate_config(wd, self.thresholds, act, self.profile)
+        with tracing.span("controller.monitor"):
+            wd = self.window.last(self.cfg.monitor_window)
+            mon = evaluate_config(wd, self.thresholds, act, self.profile)
         if (
             mon.accuracy < self.cfg.acc_constraint
             and self.window.count >= self.cfg.min_samples_to_tune
@@ -157,36 +158,33 @@ class ApparateController:
     # -- adaptation -------------------------------------------------------------
 
     def _tune(self):
-        wd = self.window.last(self.cfg.tune_window)
-        res = tune_thresholds(
-            wd,
-            self.active,
-            self.profile,
-            n_sites=self.n_sites,
-            acc_constraint=self.cfg.acc_constraint,
-        )
+        with tracing.span("controller.tune"):
+            wd = self.window.last(self.cfg.tune_window)
+            res = tune_thresholds(
+                wd,
+                self.active,
+                self.profile,
+                n_sites=self.n_sites,
+                acc_constraint=self.cfg.acc_constraint,
+            )
         self.thresholds = res.thresholds
         self.stats["tunes"] += 1
-        self.stats["tune_wall_s"] += res.wall_s
-        self.history.append(
-            {"kind": "tune", "acc": res.accuracy, "sav": res.savings_ms,
-             "sample": self.stats["samples"]}
-        )
 
     def _adjust(self):
         if self.window.count < self.cfg.min_samples_to_tune:
             return
-        wd = self.window.last(self.cfg.tune_window)
-        res = adjust_ramps(
-            wd,
-            self.active,
-            self.thresholds,
-            self.profile,
-            n_sites=self.n_sites,
-            acc_constraint=self.cfg.acc_constraint,
-            budget_frac=self.cfg.ramp_budget_frac,
-            max_slots=self.cfg.max_slots,
-        )
+        with tracing.span("controller.adjust"):
+            wd = self.window.last(self.cfg.tune_window)
+            res = adjust_ramps(
+                wd,
+                self.active,
+                self.thresholds,
+                self.profile,
+                n_sites=self.n_sites,
+                acc_constraint=self.cfg.acc_constraint,
+                budget_frac=self.cfg.ramp_budget_frac,
+                max_slots=self.cfg.max_slots,
+            )
         changed = set(res.active) != set(self.active)
         self.active = list(res.active)
         self.thresholds = res.thresholds
@@ -195,10 +193,6 @@ class ApparateController:
             self.stats["ramp_changes"] += 1
             # fresh trial ramps need records before thresholds move; tuning
             # will re-trigger via the monitor as data accrues
-        self.history.append(
-            {"kind": "adjust", "reason": res.reason, "active": list(res.active),
-             "sample": self.stats["samples"]}
-        )
 
     # -- serving-side helpers ----------------------------------------------------
 

@@ -9,14 +9,20 @@ engine with per-token early exits and KV catch-up accounting.
   PYTHONPATH=src python -m repro.launch.serve --domain cv --n 3000
   PYTHONPATH=src python -m repro.launch.serve --workers 4 --dispatch jsq
   PYTHONPATH=src python -m repro.launch.serve --mode generative --decode-tokens 16
+
+``--profile-dir DIR`` records a profiler trace of the run into ``DIR``
+with the program's spans (``repro.tracing``) on the host line, beside the
+device's operations; open it in TensorBoard's profile plugin or xprof.
 """
 from __future__ import annotations
 
 import argparse
+import contextlib
 import json
 
 import numpy as np
 
+from repro import tracing
 from repro.configs import get_bench, get_config, get_tiny
 from repro.core import ApparateController, ControllerConfig, build_profile
 from repro.data import make_decode_stream, make_image_stream, make_token_stream
@@ -336,6 +342,24 @@ def pipeline_escape_demo(tiny, params, prompts, pp, *, n_steps=16, thr=0.6):
     }
 
 
+@contextlib.contextmanager
+def profiled(profile_dir):
+    """Record the program's spans and a profiler trace into
+    ``profile_dir`` while the block runs (nothing where it is None)."""
+    if profile_dir is None:
+        yield
+        return
+    import jax
+
+    tracing.enable()
+    jax.profiler.start_trace(profile_dir)
+    try:
+        yield
+    finally:
+        jax.profiler.stop_trace()
+        tracing.disable()
+
+
 def main(argv=None):
     ap = argparse.ArgumentParser()
     ap.add_argument("--mode", default="classify", choices=["classify", "generative"])
@@ -399,6 +423,10 @@ def main(argv=None):
     ap.add_argument("--workers", type=int, default=1)
     ap.add_argument("--dispatch", default="jsq",
                     choices=["round_robin", "jsq", "slo_aware"])
+    ap.add_argument("--profile-dir", default=None, metavar="DIR",
+                    help="record a profiler trace of the run into DIR, with "
+                         "the engine, runner and controller spans beside "
+                         "the device's operations")
     args = ap.parse_args(argv)
     # env presets must land before any jax backend work in the run
     apply_preset(args.runtime_preset)
@@ -408,24 +436,25 @@ def main(argv=None):
             args.dp, args.tp = (int(x) for x in args.mesh_shape.lower().split("x"))
         except ValueError:
             ap.error("--mesh-shape must look like '<dp>x<tp>', e.g. 1x4")
-    if args.mode == "generative":
-        serve_generative(args.n if args.n is not None else 48,
-                         decode_tokens=args.decode_tokens,
-                         budget=args.budget, acc=args.acc, load=args.load,
-                         kv_block_size=args.kv_block_size, kv_blocks=args.kv_blocks,
-                         prefill_chunk=args.prefill_chunk,
-                         admission=args.admission,
-                         admission_slack=args.admission_slack,
-                         prefix_cache=args.prefix_cache,
-                         preempt=args.preempt,
-                         steps_per_sync=args.steps_per_sync,
-                         tp=args.tp, dp=args.dp, pp=args.pp)
-    else:
-        serve(args.domain, args.n if args.n is not None else 3000,
-              policy=args.policy, budget=args.budget,
-              acc=args.acc, load=args.load, workers=args.workers,
-              dispatch=args.dispatch, admission=args.admission,
-              admission_slack=args.admission_slack)
+    with profiled(args.profile_dir):
+        if args.mode == "generative":
+            serve_generative(args.n if args.n is not None else 48,
+                             decode_tokens=args.decode_tokens,
+                             budget=args.budget, acc=args.acc, load=args.load,
+                             kv_block_size=args.kv_block_size, kv_blocks=args.kv_blocks,
+                             prefill_chunk=args.prefill_chunk,
+                             admission=args.admission,
+                             admission_slack=args.admission_slack,
+                             prefix_cache=args.prefix_cache,
+                             preempt=args.preempt,
+                             steps_per_sync=args.steps_per_sync,
+                             tp=args.tp, dp=args.dp, pp=args.pp)
+        else:
+            serve(args.domain, args.n if args.n is not None else 3000,
+                  policy=args.policy, budget=args.budget,
+                  acc=args.acc, load=args.load, workers=args.workers,
+                  dispatch=args.dispatch, admission=args.admission,
+                  admission_slack=args.admission_slack)
 
 
 if __name__ == "__main__":

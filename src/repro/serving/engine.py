@@ -37,6 +37,7 @@ from typing import Dict, List, Optional, Sequence
 
 import numpy as np
 
+from repro import tracing
 from repro.serving.request import GenResponse, Request, Response
 from repro.serving.runner import PoolExhausted
 
@@ -493,11 +494,15 @@ class GenerativeAdapter:
                 return
             # admit queued requests into free slots (FCFS, step boundary)
             while self.queue and self.free:
-                if not self._admit_one(self.queue.popleft(), core):
+                r = self.queue.popleft()
+                with tracing.span("engine.admit", r.rid):
+                    admitted = self._admit_one(r, core)
+                if not admitted:
                     break  # pool-blocked: wait for live slots to drain
             if not self.slots:
                 continue
-            self._step(core)
+            with tracing.span("engine.window"):
+                self._step(core)
             core.schedule(self._now, self)
             return
 
@@ -544,6 +549,10 @@ class GenerativeAdapter:
                         sids, act, n_window, thr
                     )
                     eng.n_windows += 1
+                    if n_window < eng.cfg.steps_per_sync:
+                        eng.short_windows["prefilling" if prefilling else "finishing"] += 1
+                    if finals.shape[0] < eng.cfg.steps_per_sync:
+                        eng.n_short_windows += 1
                 else:
                     l1, u1, f1 = eng.runner.step(sids, act)
                     labels, unc, finals = l1[None], u1[None], f1[None]
@@ -556,70 +565,71 @@ class GenerativeAdapter:
         eng.peak_slots = max(eng.peak_slots, B)
         live = bool(B and eng.runner is not None and ctl is not None)
         nd = finals.shape[0] if live else 1
-        for t in range(nd):
-            if live:
-                # replay one window step: the device-decided exits are
-                # honored (forced), the records still feed adaptation, and
-                # ``act`` pins the gather set even if a mid-window _adjust
-                # changes the controller's active ramps. The per-step path
-                # keeps the bare legacy signature (stub controllers in the
-                # tests implement exactly that protocol).
-                if exits_d is None:
-                    dec = ctl.observe(labels[t], unc[t], finals[t])
+        with tracing.span("engine.replay"):
+            for t in range(nd):
+                if live:
+                    # replay one window step: the device-decided exits are
+                    # honored (forced), the records still feed adaptation, and
+                    # ``act`` pins the gather set even if a mid-window _adjust
+                    # changes the controller's active ramps. The per-step path
+                    # keeps the bare legacy signature (stub controllers in the
+                    # tests implement exactly that protocol).
+                    if exits_d is None:
+                        dec = ctl.observe(labels[t], unc[t], finals[t])
+                    else:
+                        dec = ctl.observe(labels[t], unc[t], finals[t],
+                                          forced_exits=exits_d[t], act=act)
+                    fin = finals[t]
+                    ex = np.asarray(dec.exit_sites, np.int64)  # repro: allow[host-sync] — controller decisions are already host numpy
+                    released = np.asarray(dec.released_labels)  # repro: allow[host-sync] — controller decisions are already host numpy
                 else:
-                    dec = ctl.observe(labels[t], unc[t], finals[t],
-                                      forced_exits=exits_d[t], act=act)
-                fin = finals[t]
-                ex = np.asarray(dec.exit_sites, np.int64)  # repro: allow[host-sync] — controller decisions are already host numpy
-                released = np.asarray(dec.released_labels)  # repro: allow[host-sync] — controller decisions are already host numpy
-            else:
-                fin = np.zeros(B, np.int64)
-                ex = np.full(B, -1, np.int64)
-                released = fin
-            eng.slot_history.append(B)
-            kv_now = self._pending_kv
-            step_ms = eng.profile.decode_step_time(ex, act) + (
-                chunk_ms if t == 0 else 0.0
-            )
-            start = self._now
-            end = start + kv_now + step_ms
-            self._pending_kv = 0.0
-            eng.kv_ms += kv_now
-            # releases + next-step KV deferral, grouped by exit site so the
-            # catch-up's weight traffic amortizes across this step's exits
-            kv_by_site: Dict[int, int] = {}
-            for j, sid in enumerate(sids):
-                sl = self.slots.get(sid)
-                if sl is None or sl["resp"] is None:
-                    continue  # shed at an earlier replayed step of this window
-                site = int(ex[j])
-                if site >= 0:
-                    off = release_offset(eng.profile, site, B, act)
-                    rel = min(start + kv_now + off, end)
-                else:
-                    rel = end
-                resp = sl["resp"]
-                resp.release_ms.append(rel)
-                resp.exit_sites.append(site)
-                resp.tokens.append(int(released[j]))
-                resp.final_tokens.append(int(fin[j]))
-                eng.n_tokens += 1
-                core.emit(rel, self.pool, (sl["req"].rid, len(resp.tokens) - 1))
-                done = len(resp.tokens)
-                if done >= sl["req"].n_tokens:
-                    self._finish(sid, core)  # slot reusable at the next step boundary
-                elif eng.admission is not None and eng.admission.note_token(
-                    (eng.wid, sid, sl["req"].rid), rel - resp.release_ms[-2],
-                    sl["req"].slo_ms,
-                ):
-                    self._finish(sid, core, shed=True)  # doomed mid-stream: shed
-                elif site >= 0:
-                    kv_by_site[site] = kv_by_site.get(site, 0) + 1
-            for site, cnt in kv_by_site.items():
-                self._pending_kv += eng.profile.kv_fill_cost(site, cnt)
-            eng.busy_ms += kv_now + step_ms
-            eng.n_steps += 1
-            self._now = end
+                    fin = np.zeros(B, np.int64)
+                    ex = np.full(B, -1, np.int64)
+                    released = fin
+                eng.slot_history.append(B)
+                kv_now = self._pending_kv
+                step_ms = eng.profile.decode_step_time(ex, act) + (
+                    chunk_ms if t == 0 else 0.0
+                )
+                start = self._now
+                end = start + kv_now + step_ms
+                self._pending_kv = 0.0
+                eng.kv_ms += kv_now
+                # releases + next-step KV deferral, grouped by exit site so the
+                # catch-up's weight traffic amortizes across this step's exits
+                kv_by_site: Dict[int, int] = {}
+                for j, sid in enumerate(sids):
+                    sl = self.slots.get(sid)
+                    if sl is None or sl["resp"] is None:
+                        continue  # shed at an earlier replayed step of this window
+                    site = int(ex[j])
+                    if site >= 0:
+                        off = release_offset(eng.profile, site, B, act)
+                        rel = min(start + kv_now + off, end)
+                    else:
+                        rel = end
+                    resp = sl["resp"]
+                    resp.release_ms.append(rel)
+                    resp.exit_sites.append(site)
+                    resp.tokens.append(int(released[j]))
+                    resp.final_tokens.append(int(fin[j]))
+                    eng.n_tokens += 1
+                    core.emit(rel, self.pool, (sl["req"].rid, len(resp.tokens) - 1))
+                    done = len(resp.tokens)
+                    if done >= sl["req"].n_tokens:
+                        self._finish(sid, core)  # slot reusable at the next step boundary
+                    elif eng.admission is not None and eng.admission.note_token(
+                        (eng.wid, sid, sl["req"].rid), rel - resp.release_ms[-2],
+                        sl["req"].slo_ms,
+                    ):
+                        self._finish(sid, core, shed=True)  # doomed mid-stream: shed
+                    elif site >= 0:
+                        kv_by_site[site] = kv_by_site.get(site, 0) + 1
+                for site, cnt in kv_by_site.items():
+                    self._pending_kv += eng.profile.kv_fill_cost(site, cnt)
+                eng.busy_ms += kv_now + step_ms
+                eng.n_steps += 1
+                self._now = end
         # completed prefills release their first token at step end
         for sid in sorted(self.slots):
             sl = self.slots[sid]

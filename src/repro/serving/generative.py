@@ -143,6 +143,10 @@ class GenerativeEngine:
         self.n_steps = 0
         self.n_tokens = 0
         self.n_windows = 0  # sync windows dispatched (step_multi runners)
+        # windows asked for fewer steps than steps_per_sync, by reason: a
+        # stream ends first, or a chunked prefill forces one step
+        self.short_windows = {"finishing": 0, "prefilling": 0}
+        self.n_short_windows = 0  # windows that ran fewer steps, any reason
         self.n_chunks = 0  # prefill chunks co-scheduled into steps
         self.n_shed = 0  # slots shed mid-stream by the admission policy
         self.n_preempt_swaps = 0  # pool-exhaustion victims swapped to host
@@ -193,6 +197,11 @@ class GenerativeEngine:
             # host round-trips: one controller sync per window instead of
             # one per decode step (host_syncs / tokens is the bench metric)
             out["sync_windows"] = float(self.n_windows)
+            out["short_windows"] = float(self.n_short_windows)
+            short = dict(self.short_windows)
+            if self.runner is not None and hasattr(self.runner, "short_windows"):
+                short.update(self.runner.short_windows)
+            out.update({f"short_windows_{k}": float(v) for k, v in short.items()})
         if self.runner is not None and hasattr(self.runner, "dispatches"):
             # accelerator dispatches issued by the runner across the run:
             # 1/step for the batched DecodeRunner, B/step for the per-slot

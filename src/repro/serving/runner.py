@@ -16,6 +16,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro import tracing
+
 
 def _bucket(n: int) -> int:
     b = 1
@@ -597,6 +599,9 @@ class DecodeRunner:
         self.max_slots = max_slots  # K ramp gather slots (not decode rows)
         self.n_sites = len(model.sites)
         self.dispatches = 0  # jitted decode-step calls (1/step, not 1/slot)
+        # sync windows that ran fewer steps than asked, by reason: cache
+        # headroom cut the window, or every row exited before its end
+        self.short_windows = {"headroom": 0, "early_end": 0}
         self._cache = None  # batched slot cache; rows grown on demand
         self._rows = 0 if n_slots is None else _bucket(max(n_slots, 1))
         self._cache_len = self.prompts.shape[1] + self.max_new
@@ -1254,18 +1259,20 @@ class DecodeRunner:
                     self._free_slot_blocks(slot)  # unwind the shares: retry-safe
                     raise
                 ids = [0] * len(shared) + blks
-                self._cache, lab = self._prefill_fn_paged()(
-                    self.params, self._cache, toks, jnp.asarray(ids, jnp.int32),
-                    self._xkv_ids_j(slot),
-                )
-                tok = int(np.asarray(lab).reshape(-1)[0])  # repro: allow[host-sync] — sanctioned first-token read: admission needs the prefill label
+                with tracing.span("runner.prefill", item):
+                    self._cache, lab = self._prefill_fn_paged()(
+                        self.params, self._cache, toks, jnp.asarray(ids, jnp.int32),
+                        self._xkv_ids_j(slot),
+                    )
+                    tok = int(np.asarray(lab).reshape(-1)[0])  # repro: allow[host-sync] — sanctioned first-token read: admission needs the prefill label
             if self._prefix is not None:
                 self._prefix.register(self.prompts[item], self._alloc.owned_ids(slot), tok)
         else:
-            self._cache, lab = self._prefill_fn()(
-                self.params, self._cache, toks, jnp.int32(slot)
-            )
-            tok = int(np.asarray(lab).reshape(-1)[0])  # repro: allow[host-sync] — sanctioned first-token read: admission needs the prefill label
+            with tracing.span("runner.prefill", item):
+                self._cache, lab = self._prefill_fn()(
+                    self.params, self._cache, toks, jnp.int32(slot)
+                )
+                tok = int(np.asarray(lab).reshape(-1)[0])  # repro: allow[host-sync] — sanctioned first-token read: admission needs the prefill label
         self._live.add(slot)
         self._pos[slot] = self.prompts.shape[1]
         self._tok[slot] = tok
@@ -1519,102 +1526,98 @@ class DecodeRunner:
         them — adaptation sees every token, delayed by at most one
         window, never lossy. ``n_steps=1`` is bit-identical to ``step``
         (the equivalence oracle the tests pin)."""
-        slots = self._validate_slots(slots)
-        act = self._validate_active(active)
-        k = len(act)
-        if int(n_steps) < 1:
-            raise ValueError(f"sync window needs n_steps >= 1, got {n_steps}")
-        thr = np.asarray(thresholds, np.float32).reshape(-1)  # repro: allow[host-sync] — host threshold normalization — controller thresholds are host numpy
-        if thr.shape[0] != k:
-            raise ValueError(
-                f"thresholds has {thr.shape[0]} entries for {k} active sites"
-            )
-        B = len(slots)
-        if B == 0:  # nothing in flight: no dispatch (mirrors ``step``)
-            return (np.zeros((0, k, 0), np.int64), np.zeros((0, k, 0), np.float32),
-                    np.zeros((0, 0), np.int64), np.zeros((0, 0), np.int64))
-        headroom = min(self._cache_len - int(self._pos[s]) for s in slots)
-        n = min(int(n_steps), max(1, headroom))
-        n_max = _bucket(n)
-        bucket = min(self._bucket_rows(B), self._rows)
-        free = [r for r in range(self._rows) if r not in self._live][: bucket - B]
-        dup = [slots[i % B] for i in range(bucket - B - len(free))]
-        rows = np.asarray(slots + free + dup, np.int64)  # repro: allow[host-sync] — host row-index build — no device operand
-        toks = jnp.asarray(self._tok[rows].reshape(-1, 1), jnp.int32)
-        pos = jnp.asarray(self._pos[rows], jnp.int32)
-        # FREE pad rows hold garbage — mask them out of the all-exited
-        # early-termination vote (dup rows mirror a stepped slot, so
-        # their vote is redundant either way)
-        valid = np.zeros(bucket, bool)
-        valid[:B] = True
-        valid_j = jnp.asarray(valid)
-        if self.paged:
-            # pre-claim the whole window as n sequential per-step claims:
-            # identical claim/eviction order to n ``step`` calls, so
-            # block-id assignment off the min-heap stays bit-identical.
-            # On PoolExhausted the appended tail is unwound to the
-            # pre-window watermark (CoW copies stay — they are private,
-            # content-identical replacements), leaving the claim
-            # retry-safe for the engine's preempt-and-retry loop.
-            al = self._alloc
-            base_owned = {s: int(al.owned[s]) for s in slots}
-            try:
-                for i in range(n):
-                    self._claim_step_blocks(slots, offset=i)
-            except PoolExhausted:
-                for s in slots:
-                    al.release_tail(s, base_owned[s])
-                raise
-            tables_j = self._ship_tables(rows, B, B + len(free))
+        with tracing.span("runner.prepare"):
+            slots = self._validate_slots(slots)
+            act = self._validate_active(active)
+            k = len(act)
+            if int(n_steps) < 1:
+                raise ValueError(f"sync window needs n_steps >= 1, got {n_steps}")
+            thr = np.asarray(thresholds, np.float32).reshape(-1)  # repro: allow[host-sync] — host threshold normalization — controller thresholds are host numpy
+            if thr.shape[0] != k:
+                raise ValueError(
+                    f"thresholds has {thr.shape[0]} entries for {k} active sites"
+                )
+            B = len(slots)
+            if B == 0:  # nothing in flight: no dispatch (mirrors ``step``)
+                return (np.zeros((0, k, 0), np.int64), np.zeros((0, k, 0), np.float32),
+                        np.zeros((0, 0), np.int64), np.zeros((0, 0), np.int64))
+            headroom = min(self._cache_len - int(self._pos[s]) for s in slots)
+            n = min(int(n_steps), max(1, headroom))
+            n_max = _bucket(n)
+            bucket = min(self._bucket_rows(B), self._rows)
+            free = [r for r in range(self._rows) if r not in self._live][: bucket - B]
+            dup = [slots[i % B] for i in range(bucket - B - len(free))]
+            rows = np.asarray(slots + free + dup, np.int64)  # repro: allow[host-sync] — host row-index build — no device operand
+            toks = jnp.asarray(self._tok[rows].reshape(-1, 1), jnp.int32)
+            pos = jnp.asarray(self._pos[rows], jnp.int32)
+            # FREE pad rows hold garbage — mask them out of the all-exited
+            # early-termination vote (dup rows mirror a stepped slot, so
+            # their vote is redundant either way)
+            valid = np.zeros(bucket, bool)
+            valid[:B] = True
+            if self.paged:
+                # pre-claim the whole window as n sequential per-step claims:
+                # identical claim/eviction order to n ``step`` calls, so
+                # block-id assignment off the min-heap stays bit-identical.
+                # On PoolExhausted the appended tail is unwound to the
+                # pre-window watermark (CoW copies stay — they are private,
+                # content-identical replacements), leaving the claim
+                # retry-safe for the engine's preempt-and-retry loop.
+                al = self._alloc
+                base_owned = {s: int(al.owned[s]) for s in slots}
+                try:
+                    for i in range(n):
+                        self._claim_step_blocks(slots, offset=i)
+                except PoolExhausted:
+                    for s in slots:
+                        al.release_tail(s, base_owned[s])
+                    raise
+                where = self._ship_tables(rows, B, B + len(free))
+                fn = self._decode_multi_fn_paged if k else self._decode_multi_fn_paged_noramp
+            else:
+                where = jnp.asarray(rows, jnp.int32)
+                fn = self._decode_multi_fn if k else self._decode_multi_fn_noramp
+            if n < int(n_steps):  # counted once the window's claims hold
+                self.short_windows["headroom"] += 1
+            args = (toks, pos, where)
             if k:
                 pad_act = jnp.asarray(act + [act[-1]] * (self.max_slots - k), jnp.int32)
-                self._cache, (rl, rm, fl, ex, ndv) = self._decode_multi_fn_paged(n_max)(
-                    self.params, self._cache, toks, pos, tables_j, pad_act,
-                    self._thr_device(thr), jnp.int32(n), valid_j
-                )
-            else:
-                self._cache, (rl, rm, fl, ex, ndv) = self._decode_multi_fn_paged_noramp(
-                    n_max
-                )(self.params, self._cache, toks, pos, tables_j, jnp.int32(n), valid_j)
-        else:
-            rows_j = jnp.asarray(rows, jnp.int32)
-            if k:
-                pad_act = jnp.asarray(act + [act[-1]] * (self.max_slots - k), jnp.int32)
-                self._cache, (rl, rm, fl, ex, ndv) = self._decode_multi_fn(n_max)(
-                    self.params, self._cache, toks, pos, rows_j, pad_act,
-                    self._thr_device(thr), jnp.int32(n), valid_j
-                )
-            else:
-                self._cache, (rl, rm, fl, ex, ndv) = self._decode_multi_fn_noramp(
-                    n_max
-                )(self.params, self._cache, toks, pos, rows_j, jnp.int32(n), valid_j)
+                args += (pad_act, self._thr_device(thr))
+            args += (jnp.int32(n), jnp.asarray(valid))
+            fn = fn(n_max)
+        with tracing.span("runner.dispatch"):
+            self._cache, (rl, rm, fl, ex, ndv) = fn(self.params, self._cache, *args)
         self.dispatches += 1  # ONE dispatch per window, however many steps ran
         # the executed-step count is the ONE scalar the host must learn
         # before slicing the packed outputs — the single sync per window
         # is the whole point of the design
-        nd = int(ndv)  # repro: allow[host-sync] — the one sanctioned sync per window
-        # repro: allow[host-sync] — sync-boundary record drain (replay-completeness)
-        labels = np.asarray(rl)[:nd, :k, :B].astype(np.int64)
-        # host 1.0 − maxprob in f32 is the same IEEE op the per-step
-        # program runs on device — unc stays bit-identical to ``step``
-        # repro: allow[host-sync] — sync-boundary record drain (replay-completeness)
-        unc = (np.float32(1.0) - np.asarray(rm)[:nd, :k, :B]).astype(np.float32)
-        # repro: allow[host-sync] — sync-boundary record drain (replay-completeness)
-        finals = np.asarray(fl)[:nd, :B].astype(np.int64)
-        # repro: allow[host-sync] — sync-boundary exit-mask drain
-        exits = np.asarray(ex)[:nd, :B].astype(np.int64)
-        self._pos[rows[:B]] += nd
-        self._tok[rows[:B]] = finals[nd - 1]
-        if self.paged and nd < n:
-            # early termination: return the blocks pre-claimed for steps
-            # that never ran. They were never written (executed-step
-            # writes all land within ``keep``), so releasing them cannot
-            # leak state; ``peak_blocks`` keeps the transient high-water
-            # mark by design.
-            bs = self._bs_blk
-            for s in slots:
-                keep = max(base_owned[s], (int(self._pos[s]) - 1) // bs + 1)
-                self._alloc.release_tail(s, keep)
+        with tracing.span("runner.wait"):
+            nd = int(ndv)  # repro: allow[host-sync] — the one sanctioned sync per window
+        with tracing.span("runner.drain"):
+            # repro: allow[host-sync] — sync-boundary record drain (replay-completeness)
+            labels = np.asarray(rl)[:nd, :k, :B].astype(np.int64)
+            # host 1.0 − maxprob in f32 is the same IEEE op the per-step
+            # program runs on device — unc stays bit-identical to ``step``
+            # repro: allow[host-sync] — sync-boundary record drain (replay-completeness)
+            unc = (np.float32(1.0) - np.asarray(rm)[:nd, :k, :B]).astype(np.float32)
+            # repro: allow[host-sync] — sync-boundary record drain (replay-completeness)
+            finals = np.asarray(fl)[:nd, :B].astype(np.int64)
+            # repro: allow[host-sync] — sync-boundary exit-mask drain
+            exits = np.asarray(ex)[:nd, :B].astype(np.int64)
+            self._pos[rows[:B]] += nd
+            self._tok[rows[:B]] = finals[nd - 1]
+            if nd < n:
+                self.short_windows["early_end"] += 1
+                if self.paged:
+                    # early termination: return the blocks pre-claimed for
+                    # steps that never ran. They were never written
+                    # (executed-step writes all land within ``keep``), so
+                    # releasing them cannot leak state; ``peak_blocks``
+                    # keeps the transient high-water mark by design.
+                    bs = self._bs_blk
+                    for s in slots:
+                        keep = max(base_owned[s], (int(self._pos[s]) - 1) // bs + 1)
+                        self._alloc.release_tail(s, keep)
         return labels, unc, finals, exits
 
     def free(self, slot: int) -> None:
