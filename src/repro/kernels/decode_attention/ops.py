@@ -25,7 +25,10 @@ def attend_decode(q, k, v, pos, *, use_kernel=True, interpret=False):
 @partial(jax.jit, static_argnames=("use_kernel", "interpret"))
 def attend_decode_paged(q, k_pool, v_pool, block_table, pos, *,
                         use_kernel=True, interpret=False):
-    if use_kernel:
+    # the kernel copies whole pages, which the TPU compiler can slice out
+    # of the pool only where the head size fills whole 128-lane tiles;
+    # other pools take the gathered reference
+    if use_kernel and (interpret or q.shape[-1] % 128 == 0):
         return paged_decode_attention(q, k_pool, v_pool, block_table, pos,
                                       interpret=interpret)
     return paged_decode_attention_ref(q, k_pool, v_pool, block_table, pos)

@@ -202,6 +202,9 @@ class GenerativeEngine:
             if self.runner is not None and hasattr(self.runner, "short_windows"):
                 short.update(self.runner.short_windows)
             out.update({f"short_windows_{k}": float(v) for k, v in short.items()})
+        if self.runner is not None and getattr(self.runner, "paged", False):
+            # how often the paged kernel's skip of dead pages engages
+            out.update({f"kv_pages_{k}": float(v) for k, v in self.runner.kv_pages.items()})
         if self.runner is not None and hasattr(self.runner, "dispatches"):
             # accelerator dispatches issued by the runner across the run:
             # 1/step for the batched DecodeRunner, B/step for the per-slot

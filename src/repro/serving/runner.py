@@ -602,6 +602,9 @@ class DecodeRunner:
         # sync windows that ran fewer steps than asked, by reason: cache
         # headroom cut the window, or every row exited before its end
         self.short_windows = {"headroom": 0, "early_end": 0}
+        # paged runners: pages the decode kernel attends (live rows' pages
+        # up to their positions) against the table pages it could walk
+        self.kv_pages = {"attended": 0, "table": 0}
         self._cache = None  # batched slot cache; rows grown on demand
         self._rows = 0 if n_slots is None else _bucket(max(n_slots, 1))
         self._cache_len = self.prompts.shape[1] + self.max_new
@@ -1484,9 +1487,20 @@ class DecodeRunner:
             unc = np.zeros((0, B), np.float32)
         self.dispatches += 1
         final = np.asarray(fl).reshape(-1)[:B].astype(np.int64)  # repro: allow[host-sync] — sanctioned per-step final-token drain (the sync step_multi amortizes)
+        self._count_kv_pages(rows[:B], 1)
         self._pos[rows[:B]] += 1
         self._tok[rows[:B]] = final  # vanilla greedy trajectory (agreement baseline)
         return labels, unc, final
+
+    def _count_kv_pages(self, rows: np.ndarray, nd: int) -> None:
+        """Add ``nd`` executed steps of ``rows`` (before their positions
+        advance) to ``kv_pages``: a row at ``pos`` attends to
+        ``ceil((pos + 1) / bs)`` pages of its ``_max_blocks``-wide table."""
+        if not self.paged:
+            return
+        pos = self._pos[rows][:, None] + np.arange(nd)
+        self.kv_pages["attended"] += int((pos // self._bs_blk + 1).sum())
+        self.kv_pages["table"] += len(rows) * nd * self._max_blocks
 
     def _thr_device(self, thr: np.ndarray):
         """Device-resident per-site exit thresholds, padded to
@@ -1604,6 +1618,7 @@ class DecodeRunner:
             finals = np.asarray(fl)[:nd, :B].astype(np.int64)
             # repro: allow[host-sync] — sync-boundary exit-mask drain
             exits = np.asarray(ex)[:nd, :B].astype(np.int64)
+            self._count_kv_pages(rows[:B], nd)
             self._pos[rows[:B]] += nd
             self._tok[rows[:B]] = finals[nd - 1]
             if nd < n:

@@ -278,34 +278,78 @@ def test_paged_kernel_matches_ref():
     np.testing.assert_allclose(np.asarray(o_k), np.asarray(o_r), atol=2e-6, rtol=2e-6)
 
 
-@pytest.mark.slow
+def test_paged_kernel_refuses_part_lane_heads():
+    """A head size that does not fill whole 128-lane tiles cannot be
+    sliced out of the pool a page at a time on the chip: the kernel
+    refuses it and ``attend_decode_paged`` gives such pools the gathered
+    reference."""
+    from repro.kernels.decode_attention import (
+        attend_decode_paged,
+        paged_decode_attention,
+        paged_decode_attention_ref,
+    )
+
+    rng = np.random.default_rng(2)
+    q, kp, vp, table, pos = _rand_paged(rng, 2, 4, 2, 16, 8, 3)
+    with pytest.raises(ValueError, match="multiple of 128"):
+        paged_decode_attention(q, kp, vp, table, pos)
+    np.testing.assert_array_equal(
+        np.asarray(attend_decode_paged(q, kp, vp, table, pos, use_kernel=True)),
+        np.asarray(paged_decode_attention_ref(q, kp, vp, table, pos)))
+
+
 @pytest.mark.parametrize(
-    "B,H,KH,hd,bs,nb",
+    "B,H,KH,hd,bs,nb,pos,idle,trash",
     [
-        (1, 2, 2, 8, 4, 1),  # single block: init tile is also the final tile
-        (2, 4, 2, 16, 8, 3),
-        (3, 8, 2, 32, 16, 2),  # GQA 4:1
-        (4, 4, 4, 16, 4, 5),  # MHA, many small blocks
-        (2, 6, 3, 16, 8, 4),  # 2:1 grouping
+        # single block: init tile is also the final tile
+        pytest.param(1, 2, 2, 8, 4, 1, None, 0, "finite", marks=pytest.mark.slow),
+        pytest.param(2, 4, 2, 16, 8, 3, None, 0, "finite", marks=pytest.mark.slow),
+        # GQA 4:1
+        pytest.param(3, 8, 2, 32, 16, 2, None, 0, "finite", marks=pytest.mark.slow),
+        # MHA, many small blocks
+        pytest.param(4, 4, 4, 16, 4, 5, None, 0, "finite", marks=pytest.mark.slow),
+        # 2:1 grouping
+        pytest.param(2, 6, 3, 16, 8, 4, None, 0, "finite", marks=pytest.mark.slow),
+        # compute blocks of 16 pages at bs 16: pos 0, the last token of a
+        # page, the last of a compute block and the first of the next
+        pytest.param(4, 4, 2, 16, 16, 32, [0, 15, 255, 256], 0, "finite",
+                     id="two-compute-blocks"),
+        # 21 pages: the last compute block holds 5; the first token of a
+        # page, the table's last token, and a row that skips the last block
+        pytest.param(3, 6, 2, 16, 16, 21, [16, 335, 200], 0, "finite", id="ragged-last-block"),
+        # bucket-padding rows as the runner builds them: every table entry
+        # on the trash block, at a stale position (0, and one past the table)
+        pytest.param(3, 4, 2, 16, 16, 21, [40, 0, 336], 2, "finite", id="idle-rows"),
+        # dead table entries point at a trash block full of NaN: never read
+        pytest.param(3, 4, 2, 16, 16, 21, [0, 257, 335], 0, "nan", id="nan-trash"),
     ],
 )
-def test_paged_kernel_sweep(B, H, KH, hd, bs, nb):
+def test_paged_kernel_sweep(B, H, KH, hd, bs, nb, pos, idle, trash):
     """Interpret-mode Pallas sweep over head groupings / block geometries,
-    including per-row positions at every in-block offset."""
+    including per-row positions at every in-block offset. Dead table
+    entries point at the trash block 0, as the allocator keeps them."""
     from repro.kernels.decode_attention import (
         paged_decode_attention,
         paged_decode_attention_ref,
     )
 
     rng = np.random.default_rng(B * 1000 + nb)
-    q, kp, vp, table, pos = _rand_paged(rng, B, H, KH, hd, bs, nb)
-    # force the full offset range across rows: first/last token of a block
-    pos = jnp.asarray(
-        [(i * bs + [0, bs - 1, bs // 2][i % 3]) % (nb * bs) for i in range(B)],
-        jnp.int32,
-    )
+    q, kp, vp, table, _ = _rand_paged(rng, B, H, KH, hd, bs, nb)
+    if pos is None:
+        # force the full offset range across rows: first/last token of a block
+        pos = [(i * bs + [0, bs - 1, bs // 2][i % 3]) % (nb * bs) for i in range(B)]
+    pos = jnp.asarray(pos, jnp.int32)
+    live = np.minimum(np.asarray(pos) // bs + 1, nb)
+    table = jnp.where(jnp.arange(nb)[None] < live[:, None], table, 0)
+    if idle:
+        table = table.at[B - idle:].set(0)
+    kp_r, vp_r = kp, vp
+    if trash == "nan":
+        kp, vp = kp.at[0].set(jnp.nan), vp.at[0].set(jnp.nan)
+        kp_r, vp_r = kp.at[0].set(0.0), vp.at[0].set(0.0)
     o_k = paged_decode_attention(q, kp, vp, table, pos, interpret=True)
-    o_r = paged_decode_attention_ref(q, kp, vp, table, pos)
+    o_r = paged_decode_attention_ref(q, kp_r, vp_r, table, pos)
+    assert np.isfinite(np.asarray(o_k)).all()
     np.testing.assert_allclose(np.asarray(o_k), np.asarray(o_r), atol=2e-6, rtol=2e-6)
 
 
@@ -489,6 +533,43 @@ def test_paged_memory_scales_with_live_tokens(paged_setup):
     assert cont.dispatches == paged.dispatches
     st = paged.kv_stats()
     assert st["peak_blocks"] == 6 and st["live_blocks"] == 6
+
+
+def test_kv_page_counters_follow_executed_steps(paged_setup):
+    """``kv_pages`` counts, per executed row-step, the ``ceil((pos + 1) /
+    bs)`` pages the paged kernel attends and the ``nb`` table pages it
+    could walk — through full windows, a window every row exits early
+    from, and a single ``step``."""
+    from repro.serving import DecodeRunner
+
+    _, model, params, prompts = paged_setup
+    bs = 4
+    r = DecodeRunner(model, params, prompts, max_new_tokens=8, max_slots=2,
+                     n_slots=4, kv_block_size=bs)
+    nb = r._max_blocks
+    want = {"attended": 0, "table": 0}
+
+    def run(slots, n_steps, thr):
+        pos = r._pos[slots].copy()
+        if n_steps is None:
+            r.step(slots, [0])
+            nd = 1
+        else:
+            nd = r.step_multi(slots, [0], n_steps, np.full(1, thr, np.float32))[2].shape[0]
+        for t in range(nd):
+            want["attended"] += int((-(-(pos + t + 1) // bs)).sum())
+        want["table"] += len(slots) * nd * nb
+        return nd
+
+    r.start(0, 0)
+    assert run([0], 3, 0.0) == 3
+    r.start(1, 1)
+    assert run([0, 1], None, 0.0) == 1
+    # every row's ramp fires at the first step: the window ends after it
+    assert run([0, 1], 4, 2.0) == 1 and r.short_windows["early_end"] == 1
+    assert run([0, 1], 2, 0.0) == 2
+    assert r.kv_pages == want
+    assert 0 < want["attended"] < want["table"]
 
 
 def test_paged_cache_schema_covers_all_mixer_families():

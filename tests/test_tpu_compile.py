@@ -56,7 +56,9 @@ def _has_kernel(compiled):
     return "tpu_custom_call" in compiled.as_text()
 
 
-@pytest.mark.parametrize("max_len", [160, 1024])
+# 2560: the served cell's 160 table blocks; 2000: 125 blocks, which compute
+# blocks of 16 pages do not divide
+@pytest.mark.parametrize("max_len", [160, 1024, 2560, 2000])
 def test_paged_decode_attention_compiles(one_chip, max_len):
     from repro.kernels.decode_attention import paged_decode_attention
 

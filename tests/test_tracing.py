@@ -206,8 +206,14 @@ def _serve(prefill_chunk, traced):
     orig = runner.step_multi
 
     def step_multi(slots, active, n_steps, thresholds):
+        pos = runner._pos[list(slots)].copy()
         out = orig(slots, active, n_steps, thresholds)
-        calls.append((int(n_steps), out[2].shape[0]))
+        nd = out[2].shape[0]
+        # the pages the paged kernel attends at each executed step, and
+        # the table pages it could walk
+        bs, nb = runner._bs_blk, runner._max_blocks
+        attended = sum(int((p + t) // bs + 1) for t in range(nd) for p in pos)
+        calls.append((int(n_steps), nd, attended, len(slots) * nd * nb))
         return out
 
     runner.step_multi = step_multi
@@ -252,19 +258,26 @@ def test_each_window_holds_one_of_each_runner_span(served):
     assert with_dispatch == len(calls) == stats["sync_windows"]
     # the controller's spans sit inside the replay, one decide per replayed step
     decide = np.nonzero(names == "controller.decide")[0]
-    assert len(decide) == sum(nd for _, nd in calls)
+    assert len(decide) == sum(c[1] for c in calls)
     assert all(names[s["parent"][d]] == "engine.replay" for d in decide)
 
 
 def test_short_window_counters_match_the_calls(served):
     chunk, (_, stats, calls, _), _ = served
-    asked_short = sum(1 for n, _ in calls if n < SPS)
+    asked_short = sum(1 for n, *_ in calls if n < SPS)
     assert asked_short > 0
     assert stats["short_windows_finishing"] + stats["short_windows_prefilling"] == asked_short
-    assert stats["short_windows"] == sum(1 for _, nd in calls if nd < SPS)
-    assert stats["short_windows_early_end"] == sum(1 for n, nd in calls if nd < n)
+    assert stats["short_windows"] == sum(1 for _, nd, *_ in calls if nd < SPS)
+    assert stats["short_windows_early_end"] == sum(1 for n, nd, *_ in calls if nd < n)
     assert stats["short_windows_headroom"] == 0  # slot caches hold every request whole
     assert (stats["short_windows_prefilling"] > 0) == (chunk > 0)
+
+
+def test_kv_page_counters_reach_stats(served):
+    _, (_, stats, calls, _), _ = served
+    assert stats["kv_pages_attended"] == sum(c[2] for c in calls)
+    assert stats["kv_pages_table"] == sum(c[3] for c in calls)
+    assert 0 < stats["kv_pages_attended"] < stats["kv_pages_table"]
 
 
 def test_tracing_changes_no_token_and_no_exit(served):
